@@ -46,6 +46,8 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None, metavar="SUBSTR",
                     help="run only modules whose name contains SUBSTR")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         # must precede ANY benchmarks.* import: modules size their sweeps
         # off benchmarks.common.SMOKE at import time

@@ -29,7 +29,7 @@ Masks may be (N+1,) — one shared availability for every group — or
 confirmed a Byzantine worker).
 
 ops.py dispatches here on TPU; tests run interpret=True against
-ref.fused_group_decode_ref (bit-identical by construction).
+ref.fused_group_decode_ref.
 """
 
 from __future__ import annotations
@@ -76,8 +76,14 @@ def _decode_matrix(m: jnp.ndarray, alphas: jnp.ndarray,
 
 def _make_kernel(stride: int, gather: bool):
     def kernel(m_ref, a_ref, b_ref, x_ref, o_ref, *maybe_c):
-        dec = _decode_matrix(m_ref[...].astype(jnp.float32),
-                             a_ref[...], b_ref[...])
+        # the whole (G, N+1) mask block sits in VMEM (a (1, N+1) block of
+        # it is not (8, 128)-tileable); select this group's row exactly —
+        # one nonzero term per column
+        masks = m_ref[...]
+        rows = jax.lax.broadcasted_iota(jnp.int32, masks.shape, 0)
+        m = jnp.sum(jnp.where(rows == pl.program_id(0), masks, 0.0),
+                    axis=0, keepdims=True)                   # (1, N+1)
+        dec = _decode_matrix(m, a_ref[...], b_ref[...])
         xt = x_ref[0].astype(jnp.float32)                  # (N+1, FT)
         o_ref[0] = jnp.dot(dec, xt,
                            preferred_element_type=jnp.float32
@@ -119,9 +125,7 @@ def fused_group_decode(grouped: jnp.ndarray, masks: jnp.ndarray,
     """
     g, n1, v = grouped.shape
     k = alphas.shape[0]
-    shared = masks.ndim == 1
-    m2 = masks.reshape(1, n1) if shared else masks
-    m2 = m2.astype(jnp.float32)
+    m2 = jnp.broadcast_to(masks.astype(jnp.float32), (g, n1))
 
     ft = min(FEATURE_TILE, v) if v % 128 == 0 else v
     pad_f = (-v) % ft
@@ -135,10 +139,8 @@ def fused_group_decode(grouped: jnp.ndarray, masks: jnp.ndarray,
     stride, cpt = layout if in_kernel_gather else (1, 1)
 
     grid = (g, fp // ft)
-    mask_spec = pl.BlockSpec((1, n1), (lambda gi, fi: (0, 0)) if shared
-                             else (lambda gi, fi: (gi, 0)))
     in_specs = [
-        mask_spec,
+        pl.BlockSpec((g, n1), lambda gi, fi: (0, 0)),
         pl.BlockSpec((k, 1), lambda gi, fi: (0, 0)),
         pl.BlockSpec((1, n1), lambda gi, fi: (0, 0)),
         pl.BlockSpec((1, n1, ft), lambda gi, fi: (gi, 0, fi)),

@@ -47,10 +47,10 @@ def _feature_tile(f: int) -> int:
 
 
 def _kernel(w_ref, x_ref, o_ref):
-    # w: (O, I) fp32;  x: (1, I, FT);  o: (1, O, FT)
+    # w: (O, I) fp32;  x: (I, FT);  o: (O, FT)
     w = w_ref[...].astype(jnp.float32)
-    x = x_ref[0].astype(jnp.float32)
-    o_ref[0, :, :] = jnp.dot(
+    x = x_ref[...].astype(jnp.float32)
+    o_ref[...] = jnp.dot(
         w, x, preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -79,25 +79,16 @@ def berrut_apply(weights: jnp.ndarray, x: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((o_dim, i_dim), lambda gi, fi: (0, 0)),
-            pl.BlockSpec((1, i_dim, ft), lambda gi, fi: (gi, 0, fi)),
+            pl.BlockSpec((None, i_dim, ft), lambda gi, fi: (gi, 0, fi)),
         ],
-        out_specs=pl.BlockSpec((1, o_dim, ft), lambda gi, fi: (gi, 0, fi)),
+        out_specs=pl.BlockSpec((None, o_dim, ft),
+                               lambda gi, fi: (gi, 0, fi)),
         out_shape=jax.ShapeDtypeStruct((g, o_dim, fp), x.dtype),
         interpret=interpret,
     )(weights.astype(jnp.float32), xg)
     if pad_f:
         out = out[..., :f]
     return out.reshape(*lead, o_dim, f)
-
-
-def _dispatch_kernel(w_ref, x_ref, o_ref):
-    # w: (O, I) fp32;  x: (1, I, FT);  o: (O, 1, FT) — the out block sits
-    # at (0, gi, fi) of the (O, G, F) worker-major layout, so the encode
-    # contraction and the dispatch transpose are one HBM pass.
-    w = w_ref[...].astype(jnp.float32)
-    x = x_ref[0].astype(jnp.float32)
-    o_ref[:, 0, :] = jnp.dot(
-        w, x, preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -108,7 +99,7 @@ def berrut_encode_dispatch(weights: jnp.ndarray, x: jnp.ndarray,
     (O, I) @ (G, I, F) -> (O*G, F) flat coded streams in the ``n*G + g``
     order the "worker" mesh axis shards (a contiguous 1/W slice of the
     output = one worker rank's streams).  Matches
-    ref.berrut_encode_dispatch_ref bitwise.
+    ref.berrut_encode_dispatch_ref up to float rounding.
     """
     o_dim, i_dim = weights.shape
     g, _, f = x.shape
@@ -118,18 +109,23 @@ def berrut_encode_dispatch(weights: jnp.ndarray, x: jnp.ndarray,
     xg = jnp.pad(x, ((0, 0), (0, 0), (0, pad_f))) if pad_f else x
     fp = f + pad_f
 
-    grid = (g, fp // ft)
+    nf = fp // ft
+    # The (O, G, F) worker-major block viewed as (O, G*F): each grid cell
+    # writes its (O, FT) tile at column block g*nf + fi — whole O rows and
+    # 128-aligned lanes, so the block tiles on the TPU, and the encode
+    # contraction and the dispatch transpose stay one HBM pass.
     out = pl.pallas_call(
-        _dispatch_kernel,
-        grid=grid,
+        _kernel,
+        grid=(g, nf),
         in_specs=[
             pl.BlockSpec((o_dim, i_dim), lambda gi, fi: (0, 0)),
-            pl.BlockSpec((1, i_dim, ft), lambda gi, fi: (gi, 0, fi)),
+            pl.BlockSpec((None, i_dim, ft), lambda gi, fi: (gi, 0, fi)),
         ],
-        out_specs=pl.BlockSpec((o_dim, 1, ft), lambda gi, fi: (0, gi, fi)),
-        out_shape=jax.ShapeDtypeStruct((o_dim, g, fp), x.dtype),
+        out_specs=pl.BlockSpec((o_dim, ft), lambda gi, fi: (0, gi * nf + fi)),
+        out_shape=jax.ShapeDtypeStruct((o_dim, g * fp), x.dtype),
         interpret=interpret,
     )(weights.astype(jnp.float32), xg)
+    out = out.reshape(o_dim, g, fp)
     if pad_f:
         out = out[..., :f]
     return out.reshape(o_dim * g, f)

@@ -11,16 +11,18 @@ Model code calls these; the implementation is selected by a uniform
 ``kernel_type()`` resolves the active type: the ``FORCE_KERNEL``
 override wins (tests and the dry-run pin it — the dry-run lowers for a
 512-device CPU mesh where TPU kernels cannot lower), else PALLAS on TPU
-backends and XLA everywhere else.  ``force_kernel(...)`` scopes an
-override; enum members or their string names ("pallas" / "xla" / "jnp" /
-"interpret") both coerce.  Every kernel keeps a bit-identical oracle:
-INTERPRET output equals the jitted reference.
+backends and XLA everywhere else; a backend that fails to start raises
+instead of falling back.  ``force_kernel(...)`` scopes an override; enum
+members or their string names ("pallas" / "xla" / "jnp" / "interpret")
+both coerce.  Every kernel keeps an oracle that INTERPRET output matches
+to float rounding.
 """
 
 from __future__ import annotations
 
 import contextlib
 import enum
+import functools
 from typing import Optional, Union
 
 import jax
@@ -76,10 +78,9 @@ def kernel_type() -> KernelType:
     if FORCE_KERNEL is not None:
         return KernelType.coerce(FORCE_KERNEL)
     if _PLATFORM is None:
-        try:
-            _PLATFORM = jax.devices()[0].platform
-        except RuntimeError:
-            _PLATFORM = "cpu"
+        # no fallback: a backend that fails to start raises here rather
+        # than silently serving the XLA reference path
+        _PLATFORM = jax.devices()[0].platform
     return KernelType.PALLAS if _PLATFORM == "tpu" else KernelType.XLA
 
 
@@ -103,11 +104,51 @@ def _kernel_args() -> Optional[dict]:
     return {"interpret": kt is KernelType.INTERPRET}
 
 
+def _per_device(kernel, args, logical, out_logical):
+    """Run a kernel call under the active mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    ``logical_sharding_context`` the call runs per device inside a
+    ``shard_map`` that splits each argument along its logical axes
+    (``logical``: one tuple per argument; ``out_logical``: a matching
+    pytree for the outputs).  An axis that does not divide its mesh axes
+    is replicated, so only independent axes (batch, features) may be
+    named.  Off-mesh, or already inside a ``shard_map`` body, the kernel
+    runs as is.
+    """
+    from repro.models import partitioning
+    mesh = partitioning.active_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel(*args)
+
+    def spec(a, axes):
+        return partitioning.resolve_spec(mesh, axes, shape=a.shape)
+
+    out = jax.eval_shape(kernel, *args)
+    out_specs = jax.tree.map(spec, out, out_logical)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=tuple(spec(a, ax) for a, ax in zip(args, logical)),
+        out_specs=out_specs, check_vma=False)(*args)
+
+
+def _features(x) -> tuple:
+    """Logical axes of a coding operand: only the feature axis splits."""
+    return (None,) * (x.ndim - 1) + ("coded_flat",)
+
+
+def _batch(x) -> tuple:
+    return ("batch",) + (None,) * (x.ndim - 1)
+
+
 def berrut_apply(weights: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import berrut_matmul
-        return berrut_matmul.berrut_apply(weights, x, **kw)
+        return _per_device(
+            functools.partial(berrut_matmul.berrut_apply, **kw),
+            (weights, x), ((None, None), _features(x)), _features(x))
     return ref.berrut_apply_ref(weights, x)
 
 
@@ -120,7 +161,10 @@ def berrut_encode_dispatch(weights: jnp.ndarray,
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import berrut_matmul
-        return berrut_matmul.berrut_encode_dispatch(weights, x, **kw)
+        return _per_device(
+            functools.partial(berrut_matmul.berrut_encode_dispatch, **kw),
+            (weights, x), ((None, None), _features(x)),
+            (None, "coded_flat"))
     return ref.berrut_encode_dispatch_ref(weights, x)
 
 
@@ -135,8 +179,14 @@ def fused_group_decode(grouped: jnp.ndarray, masks: jnp.ndarray,
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import berrut_decode
-        return berrut_decode.fused_group_decode(
-            grouped, masks, alphas, betas, c_vote=c_vote, **kw)
+        # the vote gather reads strided vocab columns: keep V whole then
+        vocab = (None, None, "coded_flat" if c_vote <= 0 else None)
+        return _per_device(
+            functools.partial(berrut_decode.fused_group_decode,
+                              c_vote=c_vote, **kw),
+            (grouped, masks, alphas, betas),
+            (vocab, (None,) * masks.ndim, (None,), (None,)),
+            vocab if c_vote <= 0 else (vocab, (None, None, None)))
     return ref.fused_group_decode_ref(grouped, masks, alphas, betas,
                                       c_vote=c_vote)
 
@@ -153,9 +203,12 @@ def attention(q, k, v, *, causal=True, window=None, prefix=0, softcap=0.0,
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import flash_attention
-        return flash_attention.flash_attention(
-            q, k, v, causal=causal, window=window, prefix=prefix,
-            softcap=softcap, q_offset=q_offset, **kw)
+        return _per_device(
+            functools.partial(
+                flash_attention.flash_attention, causal=causal,
+                window=window, prefix=prefix, softcap=softcap,
+                q_offset=q_offset, **kw),
+            (q, k, v), (_batch(q), _batch(k), _batch(v)), _batch(q))
     use_blocked = (ATTN_IMPL == "blocked"
                    or (ATTN_IMPL == "auto"
                        and k.shape[1] >= BLOCKED_THRESHOLD))
@@ -179,9 +232,11 @@ def decode_attention(q, k_cache, v_cache, kv_mask, *, softcap=0.0,
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import flash_decode
-        return flash_decode.flash_decode(
-            q, k_cache, v_cache, kv_mask, softcap=softcap,
-            kv_scale=kv_scale, **kw)
+        args = (q, k_cache, v_cache, kv_mask)
+        return _per_device(
+            functools.partial(flash_decode.flash_decode, softcap=softcap,
+                              kv_scale=kv_scale, **kw),
+            args, tuple(_batch(a) for a in args), _batch(q))
     if kv_scale > 0.0:
         k_cache = k_cache.astype(jnp.float32) / kv_scale
         v_cache = v_cache.astype(jnp.float32) / kv_scale
@@ -208,9 +263,13 @@ def pool_decode_attention(q, k_cache, v_cache, pos, live=None, *,
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import flash_decode
-        return flash_decode.pool_flash_decode(
-            q, k_cache, v_cache, pos, live, softcap=softcap,
-            kv_scale=kv_scale, **kw)
+        kernel = functools.partial(flash_decode.pool_flash_decode,
+                                   softcap=softcap, kv_scale=kv_scale, **kw)
+        args = (q, k_cache, v_cache, jnp.asarray(pos, jnp.int32))
+        if live is not None:
+            args += (live,)
+        return _per_device(kernel, args, tuple(_batch(a) for a in args),
+                           _batch(q))
     w = k_cache.shape[1]
     valid = jnp.arange(w)[None, :] <= jnp.asarray(pos, jnp.int32)[:, None]
     if live is not None:
@@ -227,8 +286,12 @@ def ssd(x, dt, a_log, b, c, d_skip, h0=None, chunk: int = 128):
     kw = _kernel_args()
     if kw is not None:
         from repro.kernels import ssd_scan
-        return ssd_scan.ssd_chunked(
-            x, dt, a_log, b, c, d_skip, h0=h0, chunk=chunk, **kw)
+        args = (x, dt, a_log, b, c, d_skip) + (() if h0 is None else (h0,))
+        logical = (_batch(x), _batch(dt), (None,), _batch(b), _batch(c),
+                   (None,)) + (() if h0 is None else (_batch(h0),))
+        return _per_device(
+            functools.partial(ssd_scan.ssd_chunked, chunk=chunk, **kw),
+            args, logical, (_batch(x), ("batch", None, None, None)))
     return ref.ssd_chunked_ref(x, dt, a_log, b, c, d_skip, h0=h0, chunk=chunk)
 
 

@@ -164,9 +164,10 @@ def pool_decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
                               block: int = 512) -> jnp.ndarray:
     """Oracle for ``flash_decode.pool_flash_decode`` (slot-pool decode).
 
-    Blocked online-softmax in the kernel's exact op order — same tile
-    width, same masked-exp/rescale sequence, same ``acc / max(l, 1e-30)``
-    finalisation — so the interpreted kernel matches bitwise.  The mask
+    Blocked online-softmax in the kernel's op order — same tile width
+    (``block``, or the whole ring when shorter), same masked-exp/rescale
+    sequence, same ``acc / max(l, 1e-30)`` finalisation.  The two are
+    separate programs, so they agree to float rounding, not bitwise.  The mask
     is never materialised at (B, W): each tile derives validity from the
     per-stream ``pos`` ring positions (``kvpos <= pos`` — the live
     ring-buffer slots of DESIGN.md §10) composed with the optional
@@ -181,6 +182,7 @@ def pool_decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
     w, kv = k_cache.shape[1], k_cache.shape[2]
     rep = h // kv
     scale = 1.0 / (d ** 0.5)
+    block = min(block, w)
     pad_w = (-w) % block
     kp = jnp.pad(k_cache, ((0, 0), (0, pad_w), (0, 0), (0, 0)))
     vp = jnp.pad(v_cache, ((0, 0), (0, pad_w), (0, 0), (0, 0)))

@@ -10,12 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: jax < 0.5 has neither
-    ``jax.sharding.AxisType`` nor the ``axis_types`` kwarg."""
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+    """A mesh whose axes are all ``Auto`` (GSPMD-propagated) sharding."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -67,6 +67,7 @@ import numpy as np
 
 from repro import configs
 from repro.core.scheme import get_scheme, scheme_names
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import embed_inputs, init_params
 from repro.models import predict_fn as make_predict_fn
 from repro.serving import (AdversaryConfig, ChurnModel, CodedLLMExecutor,
@@ -290,6 +291,7 @@ def run(arch: str, reduced: bool, requests: int, k: int, s: int, e: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b",
                     choices=configs.list_archs())

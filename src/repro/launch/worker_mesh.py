@@ -51,20 +51,9 @@ def _sample_tokens(logits, sample, rng):
     from repro.serving.sampling import sample_tokens
     return sample_tokens(logits, sample, rng)
 
-try:        # public namespace from jax ~0.6; experimental before that
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-except ImportError:                                      # pragma: no cover
-    _shard_map_impl = jax.shard_map
-
-
 def _smap(f, mesh, in_specs, out_specs):
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-    except TypeError:                                    # pragma: no cover
-        # newer jax renamed/dropped check_rep
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,8 +1,8 @@
 """Full-round Pallas kernel surface (DESIGN.md §16): the fused
 encode->dispatch kernel and the coded-pool flash-decode kernel vs their
-jnp oracles (bit-identical in interpret mode), the 128-aligned feature
-tiling guard, and the KernelType-dispatched XLA paths' byte-compat with
-the pre-kernel serving program."""
+jnp oracles (to float rounding in interpret mode), the 128-aligned
+feature tiling guard, and the KernelType-dispatched XLA paths' byte-compat
+with the pre-kernel serving program."""
 
 import functools
 
@@ -20,6 +20,23 @@ def _bitwise(a, b):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# Kernel vs oracle: the interpreted kernel and the jitted oracle are two
+# separate programs, so XLA may fuse, reassociate and round their float
+# ops differently; they agree to a few units in the last place, not
+# bitwise.  The tolerance is a few ulps of each dtype at the O(1)-O(10)
+# magnitudes these operands produce.  Bitwise checks stay only where
+# both sides run one program.
+_ROUNDING = {jnp.dtype(jnp.float32): dict(rtol=2e-6, atol=2e-6),
+             jnp.dtype(jnp.bfloat16): dict(rtol=8e-3, atol=8e-3)}
+
+
+def _rounding_close(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    np.testing.assert_allclose(a.astype(np.float32), b.astype(np.float32),
+                               **_ROUNDING[jnp.dtype(a.dtype)])
+
+
 # ---------------------------------------------------- encode -> dispatch
 
 def _encode_operands(cfg, g, f, dtype, seed=0):
@@ -30,8 +47,8 @@ def _encode_operands(cfg, g, f, dtype, seed=0):
 
 
 class TestEncodeDispatchKernelVsRef:
-    """interpret-mode kernel vs the JITTED jnp oracle, bit for bit (the
-    same contract as the fused decode tail in tests/test_fused_round)."""
+    """interpret-mode kernel vs the JITTED jnp oracle, to float
+    rounding (separate programs)."""
 
     @pytest.mark.parametrize("k,s,g,f", [
         (2, 1, 1, 256),
@@ -45,7 +62,7 @@ class TestEncodeDispatchKernelVsRef:
         got = berrut_matmul.berrut_encode_dispatch(w, x, interpret=True)
         want = jax.jit(ref.berrut_encode_dispatch_ref)(w, x)
         assert got.shape == (cfg.num_workers * g, f)
-        _bitwise(got, want)
+        _rounding_close(got, want)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_dtypes(self, dtype):
@@ -54,7 +71,7 @@ class TestEncodeDispatchKernelVsRef:
         got = berrut_matmul.berrut_encode_dispatch(w, x, interpret=True)
         want = jax.jit(ref.berrut_encode_dispatch_ref)(w, x)
         assert got.dtype == dtype
-        _bitwise(got, want)
+        _rounding_close(got, want)
 
     @pytest.mark.parametrize("f", [200, 1000])
     def test_ragged_feature_dims(self, f):
@@ -63,7 +80,7 @@ class TestEncodeDispatchKernelVsRef:
         w, x = _encode_operands(cfg, 2, f, jnp.float32)
         got = berrut_matmul.berrut_encode_dispatch(w, x, interpret=True)
         want = jax.jit(ref.berrut_encode_dispatch_ref)(w, x)
-        _bitwise(got, want)
+        _rounding_close(got, want)
 
     def test_matches_unfused_worker_major_composition(self):
         """The fused layout IS the pre-fused encode + swapaxes/reshape:
@@ -74,7 +91,7 @@ class TestEncodeDispatchKernelVsRef:
         fused = berrut_matmul.berrut_encode_dispatch(w, x, interpret=True)
         unfused = jnp.swapaxes(
             jax.jit(ref.berrut_apply_ref)(w, x), 0, 1).reshape(-1, f)
-        _bitwise(fused, unfused)
+        _rounding_close(fused, unfused)
 
     def test_ops_dispatch_xla_and_interpret_agree(self):
         cfg = CodingConfig(k=4, s=1)
@@ -83,7 +100,7 @@ class TestEncodeDispatchKernelVsRef:
             a = ops.berrut_encode_dispatch(w, x)
         with ops.force_kernel(ops.KernelType.XLA):
             b = jax.jit(lambda *t: ops.berrut_encode_dispatch(*t))(w, x)
-        _bitwise(a, b)
+        _rounding_close(a, b)
 
 
 class TestFeatureTileGuard:
@@ -110,13 +127,12 @@ class TestFeatureTileGuard:
 
     def test_berrut_apply_ragged_matches_ref(self):
         """berrut_apply through the padded tiling still matches its
-        oracle bitwise (padding columns are sliced off, F is not
-        contracted)."""
+        oracle (padding columns are sliced off, F is not contracted)."""
         cfg = CodingConfig(k=4, s=1)
         w, x = _encode_operands(cfg, 2, 1000, jnp.float32)
         got = berrut_matmul.berrut_apply(w, x, interpret=True)
         want = jax.jit(ref.berrut_apply_ref)(w, x)
-        _bitwise(got, want)
+        _rounding_close(got, want)
 
 
 # ------------------------------------------------- pool flash decode
@@ -141,13 +157,13 @@ def _assert_pool_kernel_matches_ref(q, k, v, pos, live, *, softcap=0.0,
     want = jax.jit(functools.partial(ref.pool_decode_attention_ref,
                                      softcap=softcap,
                                      kv_scale=kv_scale))(q, k, v, pos, live)
-    _bitwise(got, want)
+    _rounding_close(got, want)
 
 
 class TestPoolFlashDecodeVsRef:
     @pytest.mark.parametrize("h,kv", [(4, 4), (8, 4), (8, 2), (6, 1)])
     def test_gqa_head_ratios(self, h, kv):
-        """MHA, GQA rep 2/4, and MQA all hit the oracle bitwise."""
+        """MHA, GQA rep 2/4, and MQA all match the oracle."""
         b, d, w = 5, 64, 640
         q, k, v = _pool_operands(b, h, kv, d, w)
         pos = jnp.asarray([0, 3, 100, 639, 320], jnp.int32)
@@ -172,7 +188,7 @@ class TestPoolFlashDecodeVsRef:
 
     def test_masked_free_slots(self):
         """Dead slots (live == 0) output exactly zero; live slots match
-        the oracle bitwise in the same batch."""
+        the oracle in the same batch."""
         b, h, kv, d, w = 6, 4, 2, 32, 576
         q, k, v = _pool_operands(b, h, kv, d, w)
         pos = jnp.asarray([5, 40, 100, 200, 300, 575], jnp.int32)

@@ -35,11 +35,8 @@ from repro.training import TrainConfig, train_step
 from repro.serving.coded_serving import (CodedServingState,
                                          coded_decode_step, coded_prefill)
 
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((4, 2), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-else:  # jax < 0.5: Auto is the only (implicit) axis type
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # --- resolve_spec unit checks ------------------------------------------
 spec = resolve_spec(mesh, ("fsdp", "heads"), shape=(128, 8))

@@ -112,6 +112,35 @@ for sample in (SampleConfig(), SampleConfig(top_k=3, temperature=0.7)):
         assert np.array_equal(base, got), (sample, w, base, got)
 print("TOKENS-BITWISE-OK")
 
+# --- Pallas kernels under the mesh run per device ------------------------
+# GSPMD cannot partition a Mosaic kernel: ops._per_device wraps each call
+# in a shard_map over the batch / feature axis.  Interpreted kernels take
+# the same wrapper, so W=4 must reproduce W=1 token for token.
+from repro.kernels import ops
+
+with ops.force_kernel("interpret"):
+    w1 = serve(1, WorkerShardConfig(), SampleConfig())
+    w4 = serve(4, WorkerShardConfig(), SampleConfig())
+assert np.array_equal(w1, w4), (w1, w4)
+# a kernel with two outputs (SSD scan: y, final state)
+srng = np.random.RandomState(1)
+sargs = (jnp.asarray(srng.randn(8, 64, 2, 16), jnp.float32),
+         jnp.asarray(np.abs(srng.randn(8, 64, 2)) * 0.1 + 0.01, jnp.float32),
+         jnp.zeros((2,), jnp.float32),
+         jnp.asarray(srng.randn(8, 64, 8) * 0.5, jnp.float32),
+         jnp.asarray(srng.randn(8, 64, 8) * 0.5, jnp.float32),
+         jnp.ones((2,), jnp.float32))
+ssd = jax.jit(lambda *a: ops.ssd(*a, chunk=32))
+with ops.force_kernel("interpret"):
+    want = ssd(*sargs)
+    mesh = make_worker_mesh(4)
+    with mesh, partitioning.logical_sharding_context(mesh):
+        got = jax.jit(lambda *a: ops.ssd(*a, chunk=32))(*sargs)
+for g, w_ in zip(got, want):
+    np.testing.assert_allclose(np.asarray(g), np.asarray(w_), rtol=1e-5,
+                               atol=1e-5)
+print("PER-DEVICE-KERNELS-OK")
+
 
 # --- survivor-only gather moves fewer bytes than replicated --------------
 def decode_bytes(mode):

@@ -53,14 +53,13 @@ def main(argv=None) -> None:
         # off benchmarks.common.SMOKE at import time
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
-    from benchmarks import (bench_coded_round, bench_kernels, fig_acc_archs,
+    from benchmarks import (bench_kernels, fig_acc_archs,
                             fig_acc_trained_lm, fig_acc_vs_e,
                             fig_acc_vs_k, fig_acc_vs_s,
                             fig_adaptive_redundancy, fig_byzantine_serving,
                             fig_mesh_serving, fig_scheme_faceoff, fig_sigma,
                             fig_cvote_ablation, fig_systematic,
-                            fig_tail_latency, roofline_table,
-                            table_overhead)
+                            fig_tail_latency, table_overhead)
 
     modules = [
         ("fig_acc_vs_k (paper Figs 3/5/6)", fig_acc_vs_k),
@@ -82,10 +81,7 @@ def main(argv=None) -> None:
         ("fig_scheme_faceoff (paper Figs 3/5/6 + §1 overhead, one sweep)",
          fig_scheme_faceoff),
         ("table_overhead (paper §1/§4)", table_overhead),
-        ("bench_coded_round (fused round hot path, perf trajectory)",
-         bench_coded_round),
         ("bench_kernels", bench_kernels),
-        ("roofline_table (deliverable g)", roofline_table),
     ]
     if args.only:
         modules = [(t, m) for t, m in modules

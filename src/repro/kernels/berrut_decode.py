@@ -21,8 +21,7 @@ the block, tiled along the vocab axis in VMEM:
     for free instead of casting the whole (G, N+1, V) block.  (The
     serving tail itself locates BEFORE its masked decode, so it gathers
     via ``error_locator.gather_vote_values`` and uses this kernel for
-    the decode alone; the combined mode is measured as the one-pass
-    variant in ``benchmarks/bench_coded_round.py``.)
+    the decode alone.)
 
 Masks may be (N+1,) — one shared availability for every group — or
 (G, N+1) per-group exclusion masks (rounds where the locator actually

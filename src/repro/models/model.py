@@ -155,10 +155,12 @@ def prefill(cfg: ModelConfig, params: dict, inputs: dict, caches: list
             ) -> Tuple[jnp.ndarray, list]:
     """Process the full prompt; returns (last-token logits (B,V), caches)."""
     x = embed_inputs(cfg, params, inputs)
-    x, caches = transformer.prefill_runs(cfg, params["blocks"], x,
-                                         _positions(x), caches)
-    x = layers.apply_norm(cfg, params["final_norm"], x[:, -1:])
-    logits = layers.unembed(cfg, params["embeddings"], x)[:, 0]
+    with jax.named_scope("blocks"):
+        x, caches = transformer.prefill_runs(cfg, params["blocks"], x,
+                                             _positions(x), caches)
+    with jax.named_scope("unembed"):
+        x = layers.apply_norm(cfg, params["final_norm"], x[:, -1:])
+        logits = layers.unembed(cfg, params["embeddings"], x)[:, 0]
     return logits.astype(jnp.float32), caches
 
 
@@ -178,8 +180,10 @@ def decode_step(cfg: ModelConfig, params: dict, caches: list, inputs: dict,
     else:
         x = layers.embed_tokens(cfg, params["embeddings"], inputs["tokens"])
     x = shard(x, "batch", None, None)
-    x, caches = transformer.decode_runs(cfg, params["blocks"], x, pos,
-                                        caches, live=live)
-    x = layers.apply_norm(cfg, params["final_norm"], x)
-    logits = layers.unembed(cfg, params["embeddings"], x)[:, 0]
+    with jax.named_scope("blocks"):
+        x, caches = transformer.decode_runs(cfg, params["blocks"], x, pos,
+                                            caches, live=live)
+    with jax.named_scope("unembed"):
+        x = layers.apply_norm(cfg, params["final_norm"], x)
+        logits = layers.unembed(cfg, params["embeddings"], x)[:, 0]
     return logits.astype(jnp.float32), caches

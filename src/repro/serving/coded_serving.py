@@ -507,6 +507,31 @@ def _finish_pool_round(coding: CodingConfig, coded_logits: jnp.ndarray,
     return logits, None
 
 
+def _tail_and_sample(coding: CodingConfig, coded_logits: jnp.ndarray,
+                     group_mask: jnp.ndarray,
+                     straggler_mask: Optional[jnp.ndarray],
+                     with_report: bool,
+                     wshard: Optional[WorkerShardConfig],
+                     sample: Optional[SampleConfig],
+                     sample_rng: Optional[jax.Array],
+                     locate_quorum: Optional[jnp.ndarray]):
+    """A pool round's tail (locate and decode) and its token selection,
+    under the named scopes ``tail`` and ``sample`` a profile shows.  The
+    worker-sharded tail samples on its shards, inside ``tail``."""
+    with jax.named_scope("tail"):
+        if wshard is not None:
+            return _finish_pool_round(coding, coded_logits, group_mask,
+                                      straggler_mask, with_report, wshard,
+                                      sample, sample_rng,
+                                      locate_quorum=locate_quorum)
+        logits, report = _finish_pool_round(coding, coded_logits,
+                                            group_mask, straggler_mask,
+                                            with_report,
+                                            locate_quorum=locate_quorum)
+    with jax.named_scope("sample"):
+        return _maybe_sample(logits, sample, sample_rng), report
+
+
 def coded_pool_prefill(cfg: ModelConfig, coding: CodingConfig, params: dict,
                        state: CodedPoolState, inputs: dict, max_len: int,
                        admit_mask: jnp.ndarray,
@@ -541,13 +566,14 @@ def coded_pool_prefill(cfg: ModelConfig, coding: CodingConfig, params: dict,
     global CODED_PREFILL_TRACES
     CODED_PREFILL_TRACES += 1
     straggler_mask = _compose_live(straggler_mask, live_mask)
-    x = embed_inputs(cfg, params, inputs)                 # (P*K, S, d)
-    gk, s, d = x.shape
-    g = gk // coding.k
     admit_mask = jnp.asarray(admit_mask, jnp.float32)
     wm = wshard is not None
-    coded = _code_streams(coding, x.reshape(g, coding.k, s, d),
-                          worker_major=wm)
+    with jax.named_scope("encode"):
+        x = embed_inputs(cfg, params, inputs)             # (P*K, S, d)
+        gk, s, d = x.shape
+        g = gk // coding.k
+        coded = _code_streams(coding, x.reshape(g, coding.k, s, d),
+                              worker_major=wm)
     dtype = cache_dtype or jax.tree.leaves(state.caches)[0].dtype
     fresh = init_caches(cfg, coded.shape[0], max_len, dtype=dtype)
     coded_logits, fresh = prefill(cfg, params, {"embeddings": coded}, fresh)
@@ -561,17 +587,9 @@ def coded_pool_prefill(cfg: ModelConfig, coding: CodingConfig, params: dict,
         coded_logits = _corrupt_logits(coding, coded_logits, byz_mask,
                                        byz_rng, byz_sigma, byz_collude,
                                        worker_major=wm)
-    if wm:
-        out, report = _finish_pool_round(coding, coded_logits, admit_mask,
-                                         straggler_mask, with_report,
-                                         wshard, sample, sample_rng,
-                                         locate_quorum=locate_quorum)
-    else:
-        logits, report = _finish_pool_round(coding, coded_logits,
-                                            admit_mask, straggler_mask,
-                                            with_report,
-                                            locate_quorum=locate_quorum)
-        out = _maybe_sample(logits, sample, sample_rng)
+    out, report = _tail_and_sample(coding, coded_logits, admit_mask,
+                                   straggler_mask, with_report, wshard,
+                                   sample, sample_rng, locate_quorum)
     new_state = CodedPoolState(caches=caches, pos=new_pos)
     if with_report:
         return out, new_state, report
@@ -609,13 +627,15 @@ def coded_pool_decode_step(cfg: ModelConfig, coding: CodingConfig,
     CODED_DECODE_STEP_TRACES += 1
     straggler_mask = _compose_live(straggler_mask, live_mask)
     from repro.models import layers as _layers
-    x = _layers.embed_tokens(cfg, params["embeddings"], tokens)  # (P*K,1,d)
-    gk, _, d = x.shape
-    g = gk // coding.k
     active_mask = jnp.asarray(active_mask, jnp.float32)
     wm = wshard is not None
-    coded = _code_streams(coding, x.reshape(g, coding.k, 1, d),
-                          worker_major=wm)
+    with jax.named_scope("encode"):
+        x = _layers.embed_tokens(cfg, params["embeddings"],
+                                 tokens)                  # (P*K, 1, d)
+        gk, _, d = x.shape
+        g = gk // coding.k
+        coded = _code_streams(coding, x.reshape(g, coding.k, 1, d),
+                              worker_major=wm)
     pad = coded.shape[0] - g * coding.num_workers
     if wm:
         stream_pos = jnp.tile(state.pos, (coding.num_workers,))
@@ -644,18 +664,9 @@ def coded_pool_decode_step(cfg: ModelConfig, coding: CodingConfig,
         coded_logits = _corrupt_logits(coding, coded_logits, byz_mask,
                                        byz_rng, byz_sigma, byz_collude,
                                        worker_major=wm)
-    if wm:
-        out, report = _finish_pool_round(coding, coded_logits,
-                                         active_mask, straggler_mask,
-                                         with_report, wshard, sample,
-                                         sample_rng,
-                                         locate_quorum=locate_quorum)
-    else:
-        logits, report = _finish_pool_round(coding, coded_logits,
-                                            active_mask, straggler_mask,
-                                            with_report,
-                                            locate_quorum=locate_quorum)
-        out = _maybe_sample(logits, sample, sample_rng)
+    out, report = _tail_and_sample(coding, coded_logits, active_mask,
+                                   straggler_mask, with_report, wshard,
+                                   sample, sample_rng, locate_quorum)
     new_pos = state.pos + (active_mask > 0).astype(jnp.int32)
     new_state = CodedPoolState(caches=caches, pos=new_pos)
     if with_report:

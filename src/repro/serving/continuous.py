@@ -65,12 +65,23 @@ from repro.serving.scheduler import (LocateReport, apply_pool_state,
                                      check_gather_bound,
                                      derive_seed_streams, resolve_arrivals,
                                      round_ground_truth)
+from repro.serving.tracing import SpanLog
 
 # Event kinds; numeric order breaks timestamp ties (arrivals land before
 # a flush deadline at the same instant, which lands before a round).
 _ARRIVAL, _FLUSH, _ROUND = 0, 1, 2
 
 _MODES = ("continuous", "run_to_completion")
+
+
+def _named(name: str, fn):
+    """``fn`` under ``name``: jit names its program (and a profile its
+    module) after the function, and a lambda is ``<lambda>``.  The
+    lambdas look the step up in this module when traced, so a patched
+    ``coded_pool_decode_step`` reaches the executor; a nested ``def``
+    of that name would shadow it."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,24 +195,30 @@ class ContinuousLLMExecutor:
         self._key = jax.random.PRNGKey(sample_seed)
         self.max_replan_workers = coding.num_workers
         sample_cfg = self.sample
-        self._prefill = jax.jit(
+        self._prefill = jax.jit(_named(
+            "coded_pool_prefill",
             lambda p, st, t, a, m, bm, br, bs, sr, live, lq:
             coded_pool_prefill(
                 model_cfg, coding, p, st, {"tokens": t}, max_len, a,
                 straggler_mask=m, byz_mask=bm, byz_rng=br, byz_sigma=bs,
                 byz_collude=byz_collude, with_report=True,
                 sample=sample_cfg, sample_rng=sr, wshard=wshard,
-                live_mask=live, locate_quorum=lq),
+                live_mask=live, locate_quorum=lq)),
             donate_argnums=(1,))
-        self._decode = jax.jit(
+        self._decode = jax.jit(_named(
+            "coded_pool_decode_step",
             lambda p, st, t, a, m, bm, br, bs, sr, live, lq:
             coded_pool_decode_step(
                 model_cfg, coding, p, st, t, a,
                 straggler_mask=m, byz_mask=bm, byz_rng=br, byz_sigma=bs,
                 byz_collude=byz_collude, with_report=True,
                 sample=sample_cfg, sample_rng=sr, wshard=wshard,
-                live_mask=live, locate_quorum=lq),
+                live_mask=live, locate_quorum=lq)),
             donate_argnums=(1,))
+        # host spans of every call (serving/tracing.py); the scheduler
+        # writes its round spans here too
+        self.spans = SpanLog()
+        self._calls = 0
 
     def init_state(self):
         return init_pool_state(self.model_cfg, self.coding,
@@ -249,20 +266,39 @@ class ContinuousLLMExecutor:
                          jnp.int32)
         return jnp.asarray(live), lq
 
+    def _call(self, kind: str, program, state, rows: np.ndarray,
+              group_mask: np.ndarray, mask: np.ndarray,
+              attack: Optional[RoundAttack], live_mask, locate_quorum):
+        """One jitted call, in the four spans ``exec.<kind>.prepare`` /
+        ``.dispatch`` / ``.fetch`` / ``.report``."""
+        spans = self.spans
+        with spans.span(f"exec.{kind}", call=self._calls):
+            self._calls += 1
+            with spans.span(f"exec.{kind}.prepare"):
+                args = (self.params, state, jnp.asarray(rows, jnp.int32),
+                        jnp.asarray(group_mask, jnp.float32),
+                        jnp.asarray(mask, jnp.float32),
+                        *self._byz_args(attack), self._next_rng(),
+                        *self._replan_args(live_mask, locate_quorum))
+            with spans.span(f"exec.{kind}.dispatch"):
+                tokens, state, report = program(*args)
+            with spans.span(f"exec.{kind}.fetch"):
+                tokens = np.asarray(tokens)
+            with spans.span(f"exec.{kind}.report"):
+                report = self._report(mask, report)
+            # the inputs' device arrays are released inside the call's
+            # span, not after it
+            del args
+        return tokens, state, report
+
     def prefill(self, state, prompts: np.ndarray, admit_mask: np.ndarray,
                 mask: np.ndarray, attack: Optional[RoundAttack] = None,
                 live_mask: Optional[np.ndarray] = None,
                 locate_quorum: Optional[int] = None):
         """Consumes ``state`` (donated); returns ((P*K,) int32 sampled
         token ids, new state, locate report)."""
-        bm, br, bs = self._byz_args(attack)
-        live, lq = self._replan_args(live_mask, locate_quorum)
-        tokens, state, report = self._prefill(
-            self.params, state, jnp.asarray(prompts, jnp.int32),
-            jnp.asarray(admit_mask, jnp.float32),
-            jnp.asarray(mask, jnp.float32), bm, br, bs, self._next_rng(),
-            live, lq)
-        return np.asarray(tokens), state, self._report(mask, report)
+        return self._call("prefill", self._prefill, state, prompts,
+                          admit_mask, mask, attack, live_mask, locate_quorum)
 
     def decode(self, state, tokens: np.ndarray, active_mask: np.ndarray,
                mask: np.ndarray, attack: Optional[RoundAttack] = None,
@@ -270,14 +306,9 @@ class ContinuousLLMExecutor:
                locate_quorum: Optional[int] = None):
         """Consumes ``state`` (donated); returns ((P*K,) int32 sampled
         token ids, new state, locate report)."""
-        bm, br, bs = self._byz_args(attack)
-        live, lq = self._replan_args(live_mask, locate_quorum)
-        toks, state, report = self._decode(
-            self.params, state, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(active_mask, jnp.float32),
-            jnp.asarray(mask, jnp.float32), bm, br, bs, self._next_rng(),
-            live, lq)
-        return np.asarray(toks), state, self._report(mask, report)
+        return self._call("decode", self._decode, state, tokens,
+                          active_mask, mask, attack, live_mask,
+                          locate_quorum)
 
 
 class ContinuousScheduler:
@@ -513,12 +544,19 @@ class ContinuousScheduler:
     def _try_start_round(self, now: float, force: bool = False) -> None:
         if self._inflight:
             return
+        # an attempt that starts no round leaves a span without ids
+        with self.executor.spans.span("sched.start") as ids:
+            self._start_round(now, force, ids)
+
+    def _start_round(self, now: float, force: bool, ids: dict) -> None:
         self._force = force
         admitted = self._admit(now)
         self._force = False
         active = [g for g in self._slots if g is not None and g.prefilled]
         if not admitted and not active:
             return
+        ids["round"] = self._round_idx
+        ids["admitted"] = tuple(g.gid for g in admitted)
         full = self.scheme.num_workers
         # the round's operating point is pinned here: the controller may
         # retune BETWEEN rounds, never under one.  A narrower point
@@ -533,7 +571,8 @@ class ContinuousScheduler:
         # latency draws always cover the widest pool (adaptive rounds
         # slice a prefix), so the RNG stream — and the golden trace —
         # does not depend on the controller's decisions
-        times = self.latency_model.sample(self._rng, full)
+        with self.executor.spans.span("sched.latency"):
+            times = self.latency_model.sample(self._rng, full)
         # quarantined / churned-out workers are pre-masked out of the
         # wait-for selection; the quorum invariant (apply_pool_state,
         # DESIGN.md §12) early-readmits held workers rather than let the
@@ -567,6 +606,11 @@ class ContinuousScheduler:
                     times_w, float(trigger)))
 
     def _on_round(self, t: float, data) -> None:
+        with self.executor.spans.span("sched.round", round=self._round_idx):
+            self._run_round(t, data)
+        self._try_start_round(t)
+
+    def _run_round(self, t: float, data) -> None:
         (admitted, active, mask, attack, width, locate_quorum, times_w,
          trigger) = data
         self._inflight = False
@@ -591,6 +635,8 @@ class ContinuousScheduler:
             tokens, self._state, report = self.executor.decode(
                 self._state, self._token_buf, act_mask, mask, attack,
                 live_mask=live, locate_quorum=locate_quorum)
+            self.metrics.decoded_rows += sum(int((~g.done).sum())
+                                             for g in active)
             reports.append((report, act_mask))
             for g in active:
                 self._emit(g, tokens, t, first=False)
@@ -603,7 +649,6 @@ class ContinuousScheduler:
                 self._free.sort()
                 self.trace.append(("free", g.gid, g.slot, t))
         self._round_idx += 1
-        self._try_start_round(t)
 
     def _emit(self, group: SlotGroup, tokens: np.ndarray, t: float,
               first: bool) -> None:

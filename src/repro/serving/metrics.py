@@ -100,6 +100,8 @@ class ServingMetrics:
         self.churn_leaves = 0         # workers that left the pool (churn)
         self.churn_joins = 0          # workers that (re)joined the pool
         self.control_decisions = 0    # adaptive (N, E, wait_for) retunes
+        # -- slot-pool decode calls (continuous path, DESIGN.md §10) --
+        self.decoded_rows = 0         # real unretired rows decode calls served
 
     def record(self, rec: RequestRecord) -> None:
         self.records.append(rec)

@@ -156,34 +156,40 @@ def attention_prefill(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 
 
 def attention_decode(cfg: ModelConfig, p: dict, x: jnp.ndarray,
-                     pos: jnp.ndarray, cache: dict,
+                     pos: jnp.ndarray, cache: dict, i,
                      live: Optional[jnp.ndarray] = None
                      ) -> Tuple[jnp.ndarray, dict]:
-    """One-token decode: x (B, 1, d), pos scalar int32 (shared position)
+    """One-token decode at layer ``i`` of a run's stacked cache: x (B, 1,
+    d), cache leaves (L, B, W, KV, D), pos scalar int32 (shared position)
     or (B,) int32 per-stream positions (slot-pool continuous batching,
     DESIGN.md §10 — streams admitted at different rounds sit at
     different cache depths).
 
-    Writes the new KV at slot pos % width and attends over valid slots.
-    The per-stream branch never materialises a (B, W) validity mask: it
-    hands the position vector (and the optional (B,) ``live`` slot mask
-    of the coded pool) to ``ops.pool_decode_attention``, which derives
-    tile validity in-kernel on the Pallas path.  ``live`` is ignored in
-    the scalar-pos branch (one shared depth has no dead slots).
+    Writes the new KV row in place into the stacked cache, at
+    ``(i, :, pos % width)`` (scalar pos) or ``(i, rows, pos % width)``
+    (per-stream pos), attends over layer ``i``'s valid slots and returns
+    the whole stacked cache.  The per-stream branch never materialises a
+    (B, W) validity mask: it hands the position vector (and the optional
+    (B,) ``live`` slot mask of the coded pool) to
+    ``ops.pool_decode_attention``, which derives tile validity in-kernel
+    on the Pallas path.  ``live`` is ignored in the scalar-pos branch
+    (one shared depth has no dead slots).
     """
     pos = jnp.asarray(pos, jnp.int32)
-    w = cache["k"].shape[1]
+    w = cache["k"].shape[2]
     kv_scale = (INT8_KV_SCALE if cache["k"].dtype == jnp.int8 else 0.0)
     if pos.ndim == 0:
         q, k, v = _qkv(cfg, p, x, pos[None])
         slot = jnp.mod(pos, w)
-        new_k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], quantize_kv(cfg, k, cache["k"].dtype), slot, axis=1)
-        new_v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], quantize_kv(cfg, v, cache["v"].dtype), slot, axis=1)
+        new_k = jax.lax.dynamic_update_slice(
+            cache["k"], quantize_kv(cfg, k, cache["k"].dtype)[None],
+            (i, 0, slot, 0, 0))
+        new_v = jax.lax.dynamic_update_slice(
+            cache["v"], quantize_kv(cfg, v, cache["v"].dtype)[None],
+            (i, 0, slot, 0, 0))
         valid = jnp.arange(w)[None, :] <= pos             # (1, W) -> (B, W)
         valid = jnp.broadcast_to(valid, (x.shape[0], w))
-        out = ops.decode_attention(q[:, 0], new_k, new_v, valid,
+        out = ops.decode_attention(q[:, 0], new_k[i], new_v[i], valid,
                                    softcap=cfg.attn_logit_softcap,
                                    kv_scale=kv_scale)
     else:
@@ -193,11 +199,11 @@ def attention_decode(cfg: ModelConfig, p: dict, x: jnp.ndarray,
         q, k, v = _qkv(cfg, p, x, pos[:, None])
         rows = jnp.arange(x.shape[0])
         slot = jnp.mod(pos, w)
-        new_k = cache["k"].at[rows, slot].set(
+        new_k = cache["k"].at[i, rows, slot].set(
             quantize_kv(cfg, k, cache["k"].dtype)[:, 0])
-        new_v = cache["v"].at[rows, slot].set(
+        new_v = cache["v"].at[i, rows, slot].set(
             quantize_kv(cfg, v, cache["v"].dtype)[:, 0])
-        out = ops.pool_decode_attention(q[:, 0], new_k, new_v, pos,
+        out = ops.pool_decode_attention(q[:, 0], new_k[i], new_v[i], pos,
                                         live=live,
                                         softcap=cfg.attn_logit_softcap,
                                         kv_scale=kv_scale)

@@ -165,17 +165,22 @@ def block_prefill(cfg: ModelConfig, kind: str, p: dict, x, positions, cache):
     return x + y, new_cache
 
 
-def block_decode(cfg: ModelConfig, kind: str, p: dict, x, pos, cache,
+def block_decode(cfg: ModelConfig, kind: str, p: dict, x, pos, cache, i,
                  live=None):
+    """One-token decode of layer ``i``; ``cache`` is the run's stacked
+    cache, returned with layer ``i`` updated in place."""
     if kind == "S":
         # SSM state has no positional ring mask — ``live`` only gates
         # attention tiles; dead slots' SSM garbage is masked downstream.
-        y, new_cache = mamba2.mamba2_decode(
-            cfg, p["ssm"], layers.apply_norm(cfg, p["norm"], x), cache)
-        return x + y, new_cache
+        y, new_layer = mamba2.mamba2_decode(
+            cfg, p["ssm"], layers.apply_norm(cfg, p["norm"], x),
+            jax.tree.map(lambda c: c[i], cache))
+        return x + y, jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
+            cache, new_layer)
     att, new_cache = attention.attention_decode(
         cfg, p["attn"], layers.apply_norm(cfg, p["norm1"], x), pos, cache,
-        live=live)
+        i, live=live)
     x = x + att
     h = layers.apply_norm(cfg, p["norm2"], x)
     if kind == "M":
@@ -241,20 +246,23 @@ def prefill_runs(cfg: ModelConfig, blocks: dict, x, positions, caches):
 
 
 def decode_runs(cfg: ModelConfig, blocks: dict, x, pos, caches, live=None):
+    """Decode one token through all runs.  Each run's stacked cache rides
+    in the layer loop's carry, and layer ``i`` writes its new row in
+    place into it: the cache is neither scanned as ``xs`` nor re-stacked
+    as ``ys``, so the pool is held once and never copied back."""
     new_caches = []
     for (kind, count), run_p, cache in zip(
             pattern_runs(cfg.layer_pattern), blocks["runs"], caches):
-        if kind == "G":
-            x, nc = _scan(
-                cfg, lambda h, c: block_decode(cfg, "A", blocks["shared"], h,
-                                               pos, c, live=live), x, cache)
-            new_caches.append(nc)
-            continue
 
-        def body(h, pc, _kind=kind):
-            lp, c = pc
-            return block_decode(cfg, _kind, lp, h, pos, c, live=live)
+        def body(carry, pi, _kind=kind):
+            h, c = carry
+            lp, i = pi
+            if _kind == "G":
+                _kind, lp = "A", blocks["shared"]
+            return block_decode(cfg, _kind, lp, h, pos, c, i,
+                                live=live), None
 
-        x, nc = _scan(cfg, body, x, (run_p, cache))
-        new_caches.append(nc)
+        (x, cache), _ = _scan(cfg, body, (x, cache),
+                              (run_p, jnp.arange(count)))
+        new_caches.append(cache)
     return x, new_caches

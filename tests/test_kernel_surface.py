@@ -286,9 +286,10 @@ class TestAttentionDecodeLiveThreading:
                                        cfg.head_dim), jnp.float32),
         }
         pos = jnp.asarray([0, 5, 17, 31], jnp.int32)
+        cache = jax.tree.map(lambda c: c[None], cache)   # one layer
         out_none, cache_none = attention.attention_decode(
-            cfg, p, x, pos, cache)
+            cfg, p, x, pos, cache, 0)
         out_ones, cache_ones = attention.attention_decode(
-            cfg, p, x, pos, cache, live=jnp.ones((bsz,), jnp.float32))
+            cfg, p, x, pos, cache, 0, live=jnp.ones((bsz,), jnp.float32))
         _bitwise(out_none, out_ones)
         jax.tree.map(_bitwise, cache_none, cache_ones)

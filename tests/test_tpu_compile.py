@@ -11,6 +11,7 @@ load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,12 +138,10 @@ def test_op_compiles_on_worker_mesh(name, topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pool_decode_step_compiles_at_full_width(one_chip):
-    """The served E=1 decode-step program (encode, 28 layers with pool
-    attention, locate, fused decode, sampling) at qwen3-0.6b's published
-    width fits one chip, with its three kernels in place."""
+def _compiled_pool_decode_step(one_chip, coding, max_len):
+    """The served decode-step program at qwen3-0.6b's published width,
+    pool of G groups, compiled for one described chip."""
     from repro import configs
-    from repro.core.berrut import CodingConfig
     from repro.models import init_params
     from repro.serving import ContinuousLLMExecutor, SampleConfig
 
@@ -151,12 +150,11 @@ def test_pool_decode_step_compiles_at_full_width(one_chip):
             a.shape, a.dtype, sharding=one_chip), tree)
 
     cfg = configs.get_config("qwen3-0.6b")
-    coding = CodingConfig(k=K, s=1, e=1)
     n1 = coding.num_workers
     params = on_chip(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
     ex = ContinuousLLMExecutor(cfg, coding, params, pool_groups=G,
-                               max_len=PROMPT + 10, sample=SampleConfig())
+                               max_len=max_len, sample=SampleConfig())
 
     def arg(shape, dtype=F32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -166,9 +164,39 @@ def test_pool_decode_step_compiles_at_full_width(one_chip):
             arg((G * K, 1), I32), arg((G,)), arg((n1,)), arg((n1,)), key,
             arg(()), key, arg((n1,)), arg((), I32))
     with ops.force_kernel("pallas"):
-        compiled = ex._decode.lower(*args).compile()
+        return ex._decode.lower(*args).compile()
+
+
+def test_pool_decode_step_compiles_at_full_width(one_chip):
+    """The served E=1 decode-step program (encode, 28 layers with pool
+    attention, locate, fused decode, sampling) at qwen3-0.6b's published
+    width fits one chip, with its three kernels in place."""
+    from repro.core.berrut import CodingConfig
+
+    compiled = _compiled_pool_decode_step(
+        one_chip, CodingConfig(k=K, s=1, e=1), PROMPT + 10)
     assert compiled.as_text().count("tpu_custom_call") >= 3
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert held < 16e9            # one v5e chip's HBM
+
+
+def test_pool_decode_step_writes_the_pool_in_place(one_chip):
+    """The E=0 decode step at long_gen's shape (G=4, max_len 1154, K=4,
+    S=1) holds the donated KV pool once: each layer's new row is written
+    in place into the stacked pool, so no copy and no
+    dynamic-update-slice produces a whole stacked cache, and the
+    program's scratch is under the bytes of one of them (K or V)."""
+    from repro.core.berrut import CodingConfig
+
+    coding = CodingConfig(k=K, s=1, e=0)
+    max_len = 1154
+    compiled = _compiled_pool_decode_step(one_chip, coding, max_len)
+    pool = (28, G * coding.num_workers, max_len, KV, D)
+    whole_pool = re.compile(
+        r"= f32\[%s\]\{[^}]*\} (copy|dynamic-update-slice)\("
+        % ",".join(map(str, pool)))
+    assert whole_pool.findall(compiled.as_text()) == []
+    cache_bytes = 4 * int(np.prod(pool))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes

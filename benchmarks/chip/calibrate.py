@@ -52,7 +52,7 @@ def readings(cell: spec.Cell, seeds, seconds: float, log=print) -> list:
     for seed in seeds:
         seed_of = harness.seeds(seed)
         if seed != seeds[0]:
-            executor.params = params = weights.make(dims,
+            executor.params = params = weights.make(cell.arch, dims,
                                                     seed_of["weights"])
         scheduler = harness.build_scheduler(cell, executor, coding, seed_of)
         harness.warm_up(executor, scheduler, prompt_len, coding.k,
@@ -65,14 +65,15 @@ def readings(cell: spec.Cell, seeds, seconds: float, log=print) -> list:
         served, runs = window.rebuild(sched.trace, sched.groups,
                                       executor.calls, coding.k, t_start)
         results = dict(sched.results)
-        kv = correct.program_kv(executor.state, runs,
+        kv = correct.program_kv(cell.arch, executor.state, runs,
                                 window.live_groups(sched.trace),
                                 coding.workers)
         executor.state = None
         del sched
         gc.collect()
-        r = correct.compare(dims, coding, params, executor.calls, served,
-                            runs, results, kv, harness.max_len(cell.traffic),
+        r = correct.compare(cell.arch, dims, coding, params,
+                            executor.calls, served, runs, results, kv,
+                            harness.max_len(cell.traffic),
                             seed_of["sample"], references=REFERENCES,
                             controls=(CONTROL,))
         limits = cell.cell["limits"]

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import reference
-from model import Coding, Dims
+from model import Coding
 from window import Call, GroupRun, Served
 
 TARGET_TOKENS = 400           # served tokens compared, at least ...
@@ -77,14 +77,14 @@ def group_inputs(run: GroupRun, calls: List[Call], rows: List[Served]):
     return tokens, np.stack(used), outs
 
 
-def program_kv(state, runs: Dict[int, GroupRun], gids: List[int],
+def program_kv(arch, state, runs: Dict[int, GroupRun], gids: List[int],
                workers: int) -> Dict[int, tuple]:
     """First-layer cached (keys, values), each (N+1, P, kv_heads,
     head_dim), of the groups ``gids`` as the pool state the window ended
-    with holds them, P the positions each has written: its prompt, then
-    one per decode round.  A group admitted after the last call has
-    written nothing and is left out."""
-    cache = state.caches[0]
+    with holds them (where ``arch.program_first_kv`` finds them), P the
+    positions each has written: its prompt, then one per decode round.
+    A group admitted after the last call has written nothing and is left
+    out."""
     out = {}
     for gid in gids:
         run = runs.get(gid)
@@ -92,8 +92,8 @@ def program_kv(state, runs: Dict[int, GroupRun], gids: List[int],
             continue
         p = run.prompts.shape[1] + len(run.calls) - 1
         sl = slice(run.slot * workers, (run.slot + 1) * workers)
-        out[gid] = tuple(np.asarray(cache[name][0, sl, :p], np.float32)
-                         for name in ("k", "v"))
+        out[gid] = tuple(np.asarray(a, np.float32)
+                         for a in arch.program_first_kv(state, sl, p))
     return out
 
 
@@ -104,7 +104,7 @@ def kv_rel_err(got: tuple, ref: tuple) -> float:
                for g, r in zip(got, ref))
 
 
-def compare(dims: Dims, coding: Coding, params: dict, calls: List[Call],
+def compare(arch, dims, coding: Coding, params: dict, calls: List[Call],
             served: Dict[int, Served], runs: Dict[int, GroupRun],
             results: Dict[int, np.ndarray], kv: Dict[int, tuple],
             length: int, seed: int, references=(reference.REFERENCE,),
@@ -126,8 +126,8 @@ def compare(dims: Dims, coding: Coding, params: dict, calls: List[Call],
         run = runs[gid]
         rows = [s for s in served.values() if s.gid == gid and s.finished]
         tokens, used, outs = group_inputs(run, calls, rows)
-        got = reference.group_gaps(dims, coding, params, tokens, used, outs,
-                                   length, references=references,
+        got = reference.group_gaps(arch, dims, coding, params, tokens, used,
+                                   outs, length, references=references,
                                    controls=controls)
         for s in rows:
             i = s.row - run.slot * run.k
@@ -148,7 +148,7 @@ def compare(dims: Dims, coding: Coding, params: dict, calls: List[Call],
             tokens[:, run.prompts.shape[1] + i] = \
                 calls[c].tokens[run.slot * run.k:(run.slot + 1) * run.k]
         side = {n: tuple(np.asarray(a[:, :p]) for a in
-                         reference.first_layer_kv(dims, params,
+                         reference.first_layer_kv(arch, dims, params,
                                                   jnp.asarray(tokens), enc,
                                                   n))
                 for n in dict.fromkeys(tuple(references) + tuple(controls))}

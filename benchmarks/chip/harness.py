@@ -1,6 +1,7 @@
 """One run of one cell: set-up, the measured window, the check, the line.
 
-  1. weights made on the device from the seed (``weights.py``);
+  1. weights made on the device from the seed (``weights.py``), laid
+     out by the configuration's architecture module (``archs/``);
   2. the executor's two programs (pool prefill, decode step) compiled
      or loaded from the checkout's compile cache, and warmed up by a
      short serving run at the cell's shapes;
@@ -31,7 +32,7 @@ import numpy as np
 
 import spec
 import traffic as traffic_mod
-from model import coding as coding_of, dims as dims_of
+from model import coding as coding_of
 
 TRACE_DIR = spec.ROOT / ".bench_trace"
 TRACE_START = 0.3             # share of the window before the trace starts
@@ -78,22 +79,6 @@ def seeds(seed: int) -> dict:
     return {n: int(v) & 0x7FFFFFFF for n, v in zip(names, s)}
 
 
-def program_model(config: dict):
-    """The program's ModelConfig for the configuration file."""
-    from repro import configs
-    dt = config["torch_dtype"]
-    return configs.get_config(config["registry_name"]).with_updates(
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"], d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"], rope_theta=config["rope_theta"],
-        norm_eps=config["rms_norm_eps"],
-        tie_embeddings=config["tie_word_embeddings"],
-        param_dtype=dt, activation_dtype=dt)
-
-
 def max_len(traffic: dict) -> int:
     return int(traffic["prompt_len"]) + int(traffic["output_tokens"]["max"]) + 2
 
@@ -106,9 +91,9 @@ def build(cell: spec.Cell, seed_of: dict):
     from repro.serving import SampleConfig
     import driver
     import weights
-    dims, coding = dims_of(cell.config), coding_of(cell.config)
-    cfg = program_model(cell.config)
-    params = weights.make(dims, seed_of["weights"])
+    dims, coding = cell.arch.dims(cell.config), coding_of(cell.config)
+    cfg = cell.arch.program_model(cell.config)
+    params = weights.make(cell.arch, dims, seed_of["weights"])
     weights.check_layout(params, jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
     executor = driver.TimedExecutor(
@@ -292,8 +277,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     served, runs = window.rebuild(sched.trace, sched.groups, executor.calls,
                                   coding.k, t_start)
     ctx = types.SimpleNamespace(
-        cell=cell, dims=dims, coding=coding, chips=cell.chips,
-        setup_s=setup_s, t_start=t_start, t_end=t_end,
+        cell=cell, arch=cell.arch, dims=dims, coding=coding,
+        chips=cell.chips, setup_s=setup_s, t_start=t_start, t_end=t_end,
         # a traced run's host-clock shares leave the tracer's own time out
         window_s=t_end - t_start - (tracer.cost_s if tracer else 0.0),
         calls=executor.calls, served=served, runs=runs, clock=clock,
@@ -316,15 +301,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     log(f"longest host stretches (ms): {stalls(executor.calls, clock)}")
     mem = memory_peak(devices[:cell.chips])
     results = dict(sched.results)
-    kv = correct.program_kv(executor.state, runs,
+    kv = correct.program_kv(cell.arch, executor.state, runs,
                             window.live_groups(sched.trace), coding.workers)
     executor.state = None
     del sched
     gc.collect()
 
-    readings = correct.compare(dims, coding, params, executor.calls, served,
-                               runs, results, kv, max_len(cell.traffic),
-                               seed_of["sample"])
+    readings = correct.compare(cell.arch, dims, coding, params,
+                               executor.calls, served, runs, results, kv,
+                               max_len(cell.traffic), seed_of["sample"])
     ok, checks = correct.verdict(
         readings["program"][reference.REFERENCE],
         readings["result_mismatches"], cell.cell["limits"])

@@ -11,8 +11,9 @@ def read(ctx):
     for s in ctx.served.values():
         if not s.tokens:
             continue
-        flops += work.prompt_flops(ctx.dims, ctx.prompt_len)
+        flops += work.prompt_flops(ctx.arch, ctx.dims, ctx.prompt_len)
         for j in range(1, len(s.tokens)):
-            flops += work.token_flops(ctx.dims, ctx.prompt_len + j - 1)
+            flops += work.token_flops(ctx.arch, ctx.dims,
+                                     ctx.prompt_len + j - 1)
     peak = ctx.peaks["flops_bf16"] * ctx.chips
     return 100.0 * flops / (ctx.window_s * peak)

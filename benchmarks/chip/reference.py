@@ -1,10 +1,14 @@
 """Plain reference of the coded computation the program serves.
 
 Independent of the program: its own Berrut encode and decode matrices
-(float64 on the host), its own decoder-only transformer in
-straightforward ``jax.numpy`` (RMSNorm, per-head q/k RMSNorm, rotary
-embeddings, grouped-query causal attention, SwiGLU MLP, tied
-unembedding), no cache, no kernels, no batching tricks.
+(float64 on the host), and a model in straightforward ``jax.numpy`` with
+no cache, no kernels and no batching tricks.  This file holds what every
+model shares: the coding, the input embedding and the unembedding
+(through the tables the architecture module names), and the products,
+norms and rotary embeddings its layers are built from.  The layers
+themselves, and the first attention layer's cache, are the architecture
+module's (``archs/<arch>.py``, ``spec.arch_module``); each function here
+takes that module as its first argument.
 
 One group of K queries is Berrut-encoded position by position into N+1
 coded embedding sequences; each runs through the model whole; the coded
@@ -33,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import Coding, Dims
+from model import Coding
 
 POSITION_BLOCK = 64
 
@@ -93,122 +97,72 @@ NUMERICS = {"float32": ("float32", "default", "default"),
 REFERENCE = "float32"
 
 
-def _mm(eq: str, a, b, dtype, precision: str):
+def matmul(eq: str, a, b, dtype, precision: str):
     """float32 product of two operands stored in ``dtype``."""
     return jnp.einsum(eq, a.astype(dtype), b.astype(dtype),
                       precision=precision,
                       preferred_element_type=jnp.float32)
 
 
-def _rms(x, w, eps, out_dtype):
+def rms_norm(x, w, eps, out_dtype):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
     return (y * w.astype(jnp.float32)).astype(out_dtype)
 
 
-def _rope(x, cos, sin, out_dtype):
+def rope(x, cos, sin, out_dtype):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(out_dtype)
 
 
-def _rotary(dims: Dims, t: int):
+def rotary(dims, t: int):
     inv = 1.0 / dims.rope_theta ** (
         jnp.arange(0, dims.head_dim, 2, dtype=jnp.float32) / dims.head_dim)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
     return jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
 
 
-def _kv(dims: Dims, p: dict, a, cos, sin, numerics: str):
-    """One layer's cached keys (q/k-normed, rotated) and values of its
-    normed input ``a`` (S, T, d)."""
-    store, w_prec, _ = NUMERICS[numerics]
-    cdt = jnp.dtype(store)
-    k = _mm("btd,dhk->bthk", a, p["attn"]["wk"], cdt, w_prec).astype(cdt)
-    v = _mm("btd,dhk->bthk", a, p["attn"]["wv"], cdt, w_prec).astype(cdt)
-    k = _rope(_rms(k, p["attn"]["k_norm"], dims.eps, cdt), cos, sin, cdt)
-    return k, v
-
-
-def hidden_states(dims: Dims, params: dict, x: jnp.ndarray,
-                  numerics: str) -> jnp.ndarray:
-    """(S, T, d) input embeddings -> (S, T, d) final-normed states."""
-    store, w_prec, a_prec = NUMERICS[numerics]
-    cdt = jnp.dtype(store)
-    run = jax.tree.map(lambda a: a.astype(cdt), params["blocks"]["runs"][0])
-    t = x.shape[1]
-    cos, sin = _rotary(dims, t)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    rep = dims.heads // dims.kv_heads
-
-    def mm(eq, a, b, precision=w_prec):
-        return _mm(eq, a, b, cdt, precision).astype(cdt)
-
-    def layer(h, p):
-        a = _rms(h, p["norm1"]["scale"], dims.eps, cdt)
-        q = mm("btd,dhk->bthk", a, p["attn"]["wq"])
-        q = _rope(_rms(q, p["attn"]["q_norm"], dims.eps, cdt), cos, sin, cdt)
-        k, v = _kv(dims, p, a, cos, sin, numerics)
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-        s = _mm("bthk,buhk->bhtu", q, k, cdt, a_prec)
-        s = jnp.where(causal, s / np.sqrt(dims.head_dim), -jnp.inf)
-        pr = jax.nn.softmax(s, axis=-1).astype(cdt)
-        o = mm("bhtu,buhk->bthk", pr, v, a_prec)
-        h = h + mm("bthk,hkd->btd", o, p["attn"]["wo"])
-        m = _rms(h, p["norm2"]["scale"], dims.eps, cdt)
-        g = (jax.nn.silu(mm("btd,df->btf", m, p["mlp"]["w_gate"]))
-             * mm("btd,df->btf", m, p["mlp"]["w_in"]))
-        return h + mm("btf,fd->btd", g, p["mlp"]["w_out"]), None
-
-    h, _ = jax.lax.scan(layer, x.astype(cdt), run)
-    return _rms(h, params["final_norm"]["scale"], dims.eps, cdt)
-
-
-def coded_inputs(dims: Dims, params: dict, tokens: jnp.ndarray,
+def coded_inputs(arch, dims, params: dict, tokens: jnp.ndarray,
                  enc: jnp.ndarray, numerics: str) -> jnp.ndarray:
     """(K, T) token ids -> (N+1, T, d) coded input embeddings."""
     store, _, a_prec = NUMERICS[numerics]
     cdt = jnp.dtype(store)
-    table = params["embeddings"]["embed"].astype(cdt)
+    table = arch.embedding(params).astype(cdt)
     emb = jnp.take(table, tokens, axis=0) * jnp.asarray(dims.embed_mult, cdt)
-    return _mm("ik,ktd->itd", enc, emb, cdt, a_prec).astype(cdt)
+    return matmul("ik,ktd->itd", enc, emb, cdt, a_prec).astype(cdt)
 
 
-def decoded_block(params: dict, h: jnp.ndarray, pos: jnp.ndarray,
+def decoded_block(arch, params: dict, h: jnp.ndarray, pos: jnp.ndarray,
                   dec: jnp.ndarray, numerics: str) -> jnp.ndarray:
     """Decoded float32 logits (K, B, V) at positions ``pos`` (B,) of the
     coded states ``h`` (N+1, T, d), with one decode matrix per position
     ``dec`` (B, K, N+1)."""
     store, w_prec, a_prec = NUMERICS[numerics]
     cdt = jnp.dtype(store)
-    coded = _mm("sbd,vd->sbv", h[:, pos], params["embeddings"]["embed"],
-                cdt, w_prec).astype(cdt)
-    return _mm("bks,sbv->kbv", dec, coded, cdt, a_prec).astype(cdt) \
+    coded = matmul("sbd,vd->sbv", h[:, pos], arch.unembedding(params),
+                   cdt, w_prec).astype(cdt)
+    return matmul("bks,sbv->kbv", dec, coded, cdt, a_prec).astype(cdt) \
         .astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def _states(dims, params, tokens, enc, numerics):
-    x = coded_inputs(dims, params, tokens, enc, numerics)
-    return hidden_states(dims, params, x, numerics)
+@functools.partial(jax.jit, static_argnums=(0, 1, 5))
+def _states(arch, dims, params, tokens, enc, numerics):
+    x = coded_inputs(arch, dims, params, tokens, enc, numerics)
+    return arch.hidden_states(dims, params, x, numerics)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def first_layer_kv(dims, params, tokens, enc, numerics):
-    """(K, T) token ids -> the first layer's cached keys and values
-    (N+1, T, kv_heads, head_dim) of the coded streams, in float32."""
-    store = jnp.dtype(NUMERICS[numerics][0])
-    p = jax.tree.map(lambda a: a[0].astype(store),
-                     params["blocks"]["runs"][0])
-    x = coded_inputs(dims, params, tokens, enc, numerics)
-    cos, sin = _rotary(dims, tokens.shape[1])
-    a = _rms(x, p["norm1"]["scale"], dims.eps, store)
-    k, v = _kv(dims, p, a, cos, sin, numerics)
+@functools.partial(jax.jit, static_argnums=(0, 1, 5))
+def first_layer_kv(arch, dims, params, tokens, enc, numerics):
+    """(K, T) token ids -> the first attention layer's cached keys and
+    values (N+1, T, kv_heads, head_dim) of the coded streams, in
+    float32."""
+    x = coded_inputs(arch, dims, params, tokens, enc, numerics)
+    k, v = arch.first_kv(dims, params, x, numerics)
     return k.astype(jnp.float32), v.astype(jnp.float32)
 
 
-_decoded = jax.jit(decoded_block, static_argnums=(4,))
+_decoded = jax.jit(decoded_block, static_argnums=(0, 5))
 
 
 @jax.jit
@@ -218,7 +172,7 @@ def _gaps(ref, ids):
     return jnp.max(ref, axis=-1) - got
 
 
-def group_gaps(dims: Dims, c: Coding, params: dict, tokens: np.ndarray,
+def group_gaps(arch, dims, c: Coding, params: dict, tokens: np.ndarray,
                used: np.ndarray, served: np.ndarray, length: int,
                references=(REFERENCE,), controls=()) -> dict:
     """Gaps of one group, by (who, reference).
@@ -240,7 +194,7 @@ def group_gaps(dims: Dims, c: Coding, params: dict, tokens: np.ndarray,
     padded = np.zeros((k, length), np.int32)
     padded[:, :t] = tokens
     enc = jnp.asarray(encode_matrix(c), jnp.float32)
-    states = {n: _states(dims, params, jnp.asarray(padded), enc, n)
+    states = {n: _states(arch, dims, params, jnp.asarray(padded), enc, n)
               for n in dict.fromkeys(tuple(references) + tuple(controls))}
     out = {(w, r): np.zeros((k, j)) for w in ("program", *controls)
            for r in references}
@@ -251,7 +205,7 @@ def group_gaps(dims: Dims, c: Coding, params: dict, tokens: np.ndarray,
         pos = jnp.asarray(prompt - 1 + idx, jnp.int32)
         dec = jnp.asarray(np.stack([decode_matrix(c, used[i]) for i in idx]),
                           jnp.float32)
-        logits = {n: _decoded(params, h, pos, dec, n)
+        logits = {n: _decoded(arch, params, h, pos, dec, n)
                   for n, h in states.items()}
         ids = {"program": jnp.asarray(served[:, idx], jnp.int32)}
         ids.update({w: jnp.argmax(logits[w], axis=-1).astype(jnp.int32)

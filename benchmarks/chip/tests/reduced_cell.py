@@ -25,18 +25,11 @@ class StandInTPU:
         return {"peak_bytes_in_use": 1}
 
 
-def reduced_config(config: dict) -> dict:
-    from repro import configs
-    r = configs.get_reduced(config["registry_name"])
-    return dict(config, num_hidden_layers=r.num_layers,
-                hidden_size=r.d_model, num_attention_heads=r.num_heads,
-                num_key_value_heads=r.num_kv_heads, head_dim=r.head_dim,
-                intermediate_size=r.d_ff, vocab_size=r.vocab_size)
-
-
 def reduced_cell(workload: str, **replace) -> spec.Cell:
+    """The cell with its configuration at the program's reduced size, as
+    its architecture module's ``reduced`` gives it."""
     cell = spec.load_cell(workload)
-    fields = dict(cell.__dict__, config=reduced_config(cell.config))
+    fields = dict(cell.__dict__, config=cell.arch.reduced(cell.config))
     fields.update(replace)
     return spec.Cell(**fields)
 

@@ -1,7 +1,10 @@
-"""CPU rehearsal of the chip benchmark: one cell's files at the
-program's reduced size through the harness functions, and the lookup of
-configurations, traffic mixes, cells and metrics by name."""
+"""CPU rehearsal of the chip benchmark: each configuration's first cell
+at the program's reduced size through the harness functions, and the
+lookup of architectures, configurations, traffic mixes, cells and
+metrics by name."""
 
+import dataclasses
+import hashlib
 import json
 import math
 import time
@@ -19,13 +22,25 @@ import window  # noqa: E402
 SEED = 2 ** 31 + 12345          # past 32 signed bits, as the driver's are
 
 
+def first_cells() -> list:
+    """The first cell of each configuration in BENCHMARK.json."""
+    first = {}
+    for w in spec.load_json(spec.ROOT / "BENCHMARK.json")["workloads"]:
+        first.setdefault(w["config"], w["name"])
+    return list(first.values())
+
+
 @pytest.fixture(scope="module")
 def cell():
     return rc.short_cell("qwen3-0.6b.e0.long_gen")
 
 
+@pytest.mark.parametrize("workload", first_cells())
 @pytest.mark.parametrize("trace", [False, True])
-def test_last_line_keys(cell, trace):
+def test_last_line_keys(workload, trace):
+    """A run's result line, each configuration at the size its
+    architecture module's ``reduced`` gives."""
+    cell = rc.short_cell(workload)
     with rc.no_compile_cache():
         out = harness.run_cell(cell, SEED, 3.0, trace, [rc.StandInTPU()],
                                time.perf_counter(), log=lambda s: None)
@@ -99,14 +114,61 @@ def test_same_work_for_every_seed():
                               np.argsort(gaps[:traffic.BLOCK]))
 
 
+UNTIED_ARCH = '''"""qwen3_dense with an untied output head."""
+
+import jax
+
+import spec
+import weights
+
+dense = spec.arch_module("qwen3_dense")
+Dims, dims, matmul_params, reduced = (dense.Dims, dense.dims,
+                                      dense.matmul_params, dense.reduced)
+hidden_states, first_kv, embedding, program_first_kv = (
+    dense.hidden_states, dense.first_kv, dense.embedding,
+    dense.program_first_kv)
+
+
+def program_model(config):
+    return dense.program_model(config).with_updates(tie_embeddings=False)
+
+
+def init(d, key):
+    key, head = jax.random.split(key)
+    tree = dense.init(d, key)
+    tree["embeddings"]["lm_head"] = weights.normal(
+        head, (d.hidden, d.vocab), d.hidden ** -0.5)
+    return tree
+
+
+def unembedding(params):
+    return params["embeddings"]["lm_head"].T
+'''
+
+
+def _digests(root) -> dict:
+    return {str(p): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in sorted(root.rglob("*"))
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
 def test_new_files_are_found_by_name(tmp_path):
-    """A configuration, mix, arrival process, cell and metric added as
-    files with a BENCHMARK.json entry, and no edit to any existing file."""
-    for d in ("configs", "traffic", "arrivals", "cells", "metrics"):
+    """An architecture, configuration, mix, arrival process, cell and
+    metric added as files with a BENCHMARK.json entry, and no edit to any
+    existing file.  The architecture, a module that reuses
+    ``qwen3_dense`` with an untied head, runs through the harness at the
+    program's reduced size and comes out correct."""
+    existing = _digests(spec.HERE)
+    bench_file = spec.ROOT / "BENCHMARK.json"
+    bench_before = bench_file.read_bytes()
+    for d in ("archs", "configs", "traffic", "arrivals", "cells",
+              "metrics"):
         (tmp_path / d).mkdir()
+    (tmp_path / "archs" / "qwen3_untied.py").write_text(UNTIED_ARCH)
     config = spec.load_json(spec.HERE / "configs" / "qwen3-0.6b.e0.json")
-    (tmp_path / "configs" / "new-model.json").write_text(
-        json.dumps(dict(config, num_hidden_layers=3)))
+    (tmp_path / "configs" / "new-model.json").write_text(json.dumps(
+        dict(config, num_hidden_layers=3, arch="qwen3_untied",
+             tie_word_embeddings=False)))
     (tmp_path / "traffic" / "new_mix.json").write_text(
         json.dumps(dict(rc.SHORT_TRAFFIC, arrivals="every_second")))
     (tmp_path / "arrivals" / "every_second.py").write_text(
@@ -114,9 +176,12 @@ def test_new_files_are_found_by_name(tmp_path):
         "def count(mix, rate_rps, seconds):\n    return 10\n\n\n"
         "def times_ms(mix, rate_rps, q):\n"
         "    return 1e3 * np.arange(len(q))\n")
+    # four groups, as the rehearsal's own cell: with two, both can be
+    # admitted in the round the window closes, leaving no cache to compare
     (tmp_path / "cells" / "new-model.new_mix.json").write_text(json.dumps(
-        {"pool_groups": 2, "rate_rps": None,
-         "limits": {"max_logit_gap": 0.1, "min_tokens_compared": 1}}))
+        {"pool_groups": 4, "rate_rps": None,
+         "limits": {"max_logit_gap": 0.25, "kv_rel_err": 5e-4,
+                    "min_tokens_compared": 50}}))
     (tmp_path / "metrics" / "new.metric.py").write_text(
         "def read(ctx):\n    return ctx.answer\n")
     bench = {
@@ -132,7 +197,7 @@ def test_new_files_are_found_by_name(tmp_path):
     c = spec.load_cell("new-model.new_mix", bench, root=tmp_path,
                        here=tmp_path)
     assert c.config["num_hidden_layers"] == 3
-    assert c.cell["pool_groups"] == 2
+    assert c.cell["pool_groups"] == 4
     assert [m.name for m in c.per_layer] == ["new.metric"]
     read = spec.metric_reader("new.metric", tmp_path / "metrics")
     assert read(type("Ctx", (), {"answer": 42.0})) == 42.0
@@ -141,3 +206,29 @@ def test_new_files_are_found_by_name(tmp_path):
     assert got.prompts.shape == (traffic.BLOCK, 16)
     np.testing.assert_array_equal(got.arrival_ms,
                                   1e3 * np.arange(traffic.BLOCK))
+
+    assert c.arch.program_model(c.config).tie_embeddings is False
+    # the run's traffic is a backlog: the harness finds arrival processes
+    # in its own directory
+    run = dataclasses.replace(c, config=c.arch.reduced(c.config),
+                              traffic=rc.SHORT_TRAFFIC)
+    with rc.no_compile_cache():
+        out = harness.run_cell(run, SEED, 3.0, False, [rc.StandInTPU()],
+                               time.perf_counter(), log=lambda s: None)
+    assert out["correct"] is True, out["checks"]
+    assert out["checks"]["kv_groups_min"]["value"] >= 1
+    assert _digests(spec.HERE) == existing
+    assert bench_file.read_bytes() == bench_before
+
+
+def test_configuration_without_arch_is_refused(tmp_path):
+    (tmp_path / "configs").mkdir()
+    config = spec.load_json(spec.HERE / "configs" / "qwen3-0.6b.e0.json")
+    config.pop("arch")
+    (tmp_path / "configs" / "old.json").write_text(json.dumps(config))
+    bench = {"configs": [{"name": "old", "file": "configs/old.json"}],
+             "workloads": [{"name": "old.long_gen", "config": "old",
+                            "traffic": "long_gen", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    with pytest.raises(KeyError, match="'arch'"):
+        spec.load_cell("old.long_gen", bench, root=tmp_path)

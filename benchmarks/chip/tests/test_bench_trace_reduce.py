@@ -77,14 +77,15 @@ def test_kernel_time_by_name():
                                 ("%fusion.1 = f32[8] fusion(...)", 20e-9)]
 
 
-DIMS = model.Dims(layers=2, hidden=8, heads=4, kv_heads=2, head_dim=2,
+DENSE = spec.arch_module("qwen3_dense")
+DIMS = DENSE.Dims(layers=2, hidden=8, heads=4, kv_heads=2, head_dim=2,
                   ffn=16, vocab=10, rope_theta=1e4, eps=1e-6,
                   embed_mult=1.0, dtype="float32")
 
 
 def test_params_met_in_products():
     # per layer: q 8x4x2 + o 4x2x8 = 128, k,v 8x2x2 x2 = 64, mlp 3x8x16
-    assert DIMS.params_matmul == 2 * (128 + 64 + 384) + 10 * 8
+    assert DENSE.matmul_params(DIMS) == 2 * (128 + 64 + 384) + 10 * 8
 
 
 def test_pool_attention_counts():
@@ -102,9 +103,10 @@ def test_tail_counts():
 
 
 def test_token_and_prompt_flops():
-    assert work.token_flops(DIMS, 0) == 2 * DIMS.params_matmul + 2 * 32
-    assert work.prompt_flops(DIMS, 3) == pytest.approx(
-        sum(work.token_flops(DIMS, d) for d in range(3)))
+    assert (work.token_flops(DENSE, DIMS, 0)
+            == 2 * DENSE.matmul_params(DIMS) + 2 * 32)
+    assert work.prompt_flops(DENSE, DIMS, 3) == pytest.approx(
+        sum(work.token_flops(DENSE, DIMS, d) for d in range(3)))
 
 
 def test_roofline_bound():
